@@ -186,4 +186,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    # the process-wide compile cache belongs to the command line run, not
+    # to in-process callers such as `repro obs bench run`
+    from repro.core.jaxenv import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

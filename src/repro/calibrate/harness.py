@@ -7,7 +7,8 @@ thunk that runs the matching real kernel (`repro.kernels.ops` wrappers:
 flash_attention / decode_attention / moe_gemm / rglru_scan, plain jnp for
 dense GEMM), then asks the pluggable timer for a latency.  The timer
 decides whether the thunk actually executes: :class:`WallClockTimer` runs
-it (interpret mode on CPU, compiled on TPU), :class:`DeterministicTimer`
+it (compiled, or through the Pallas interpreter when the harness is built
+with ``interpret=True`` on a host with no TPU), :class:`DeterministicTimer`
 prices the descriptor analytically with a fixed skew — same harness, same
 samples schema, CI-deterministic.
 
@@ -75,7 +76,7 @@ class FamilySpec:
     family: str
     axes: Tuple[Tuple[float, ...], ...]
     build_op: Callable[..., object]       # coords -> operator descriptor
-    make_thunk: Callable[..., Thunk]      # coords -> kernel runner
+    make_thunk: Callable[..., Thunk]      # (coords, interpret) -> runner
 
 
 # -- per-family op builders + kernel thunks ---------------------------------
@@ -84,7 +85,7 @@ def _gemm_op(m, n, k):
     return ops.GEMM(int(m), int(n), int(k), "bf16")
 
 
-def _gemm_thunk(m, n, k):
+def _gemm_thunk(m, n, k, interpret=False):   # plain jnp: nothing to interpret
     import jax
     import jax.numpy as jnp
     mm = jax.jit(lambda a, b: a @ b)
@@ -100,7 +101,7 @@ def _attn_prefill_op(q_len, kv_len):
         kind="gqa", dtype="bf16")
 
 
-def _attn_prefill_thunk(q_len, kv_len):
+def _attn_prefill_thunk(q_len, kv_len, interpret=False):
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as kops
@@ -113,7 +114,8 @@ def _attn_prefill_thunk(q_len, kv_len):
     v = jax.random.normal(
         ks[2], (1, int(kv_len), ATTN_KV_HEADS, ATTN_HEAD_DIM), jnp.bfloat16)
     return lambda: kops.flash_attention(q, k, v, causal=True,
-                                        block_q=128, block_k=128)
+                                        block_q=128, block_k=128,
+                                        interpret=interpret)
 
 
 def _attn_decode_op(batch, kv_len):
@@ -123,7 +125,7 @@ def _attn_decode_op(batch, kv_len):
         kind="gqa", dtype="bf16")
 
 
-def _attn_decode_thunk(batch, kv_len):
+def _attn_decode_thunk(batch, kv_len, interpret=False):
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as kops
@@ -137,7 +139,8 @@ def _attn_decode_thunk(batch, kv_len):
     vc = jax.random.normal(
         ks[2], (b, w, ATTN_KV_HEADS, ATTN_HEAD_DIM), jnp.bfloat16)
     vl = jnp.full((b,), w, jnp.int32)
-    return lambda: kops.decode_attention(q, kc, vc, vl, block_k=128)
+    return lambda: kops.decode_attention(q, kc, vc, vl, block_k=128,
+                                         interpret=interpret)
 
 
 def _moe_op(rank_tokens):
@@ -147,7 +150,7 @@ def _moe_op(rank_tokens):
         hot_rank_tokens=int(rank_tokens), dtype="bf16")
 
 
-def _moe_thunk(rank_tokens):
+def _moe_thunk(rank_tokens, interpret=False):
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as kops
@@ -165,9 +168,9 @@ def _moe_thunk(rank_tokens):
 
     def run():
         # the operator's 3 expert GEMMs (gate/up/down), end to end
-        g = kops.moe_gemm(xe, w_gate)
-        u = kops.moe_gemm(xe, w_up)
-        return kops.moe_gemm(g * u, w_down)
+        g = kops.moe_gemm(xe, w_gate, interpret=interpret)
+        u = kops.moe_gemm(xe, w_up, interpret=interpret)
+        return kops.moe_gemm(g * u, w_down, interpret=interpret)
 
     return run
 
@@ -177,7 +180,7 @@ def _recurrent_op(tokens):
                            width=REC_WIDTH, heads=1, dtype="bf16")
 
 
-def _recurrent_thunk(tokens):
+def _recurrent_thunk(tokens, interpret=False):
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as kops
@@ -188,7 +191,7 @@ def _recurrent_thunk(tokens):
         jax.random.normal(ks[0], (1, s, REC_WIDTH), jnp.float32))
     b = jax.random.normal(ks[1], (1, s, REC_WIDTH), jnp.float32)
     h0 = jnp.zeros((1, REC_WIDTH), jnp.float32)
-    return lambda: kops.rglru_scan(a, b, h0)
+    return lambda: kops.rglru_scan(a, b, h0, interpret=interpret)
 
 
 _SPEC_BUILDERS = {
@@ -208,7 +211,7 @@ class MeasurementHarness:
                  timer=None, points_per_axis: int = 3,
                  families: Optional[Sequence[str]] = None,
                  axes_override: Optional[Dict[str, Sequence[Sequence[float]]]]
-                 = None):
+                 = None, interpret: bool = False):
         self.platform = (platform if isinstance(platform, Platform)
                          else get_platform(platform))
         self.backend = backend
@@ -222,6 +225,7 @@ class MeasurementHarness:
                 f"measurable: {', '.join(MEASURED_FAMILIES)}")
         self.families = families
         self._axes_override = dict(axes_override or {})
+        self.interpret = interpret
 
     def spec(self, family: str) -> FamilySpec:
         build_op, make_thunk = _SPEC_BUILDERS[family]
@@ -236,8 +240,8 @@ class MeasurementHarness:
         for coords in itertools.product(*spec.axes):
             op = spec.build_op(*coords)
             predicted = analytical.latency(self.platform, op)
-            measured = self.timer.time(op, _deferred(spec.make_thunk,
-                                                     coords))
+            measured = self.timer.time(op, _deferred(
+                spec.make_thunk, coords, self.interpret))
             samples.append(Sample(
                 family=family, coords=tuple(float(c) for c in coords),
                 predicted_s=predicted, measured_s=measured))
@@ -250,14 +254,14 @@ class MeasurementHarness:
         return out
 
 
-def _deferred(make_thunk, coords) -> Thunk:
+def _deferred(make_thunk, coords, interpret: bool) -> Thunk:
     """Defer even thunk CONSTRUCTION (jax import, jit wrapper) to the
     first call: a timer that never executes the kernel never pays it."""
     state: dict = {}
 
     def thunk():
         if "t" not in state:
-            state["t"] = make_thunk(*coords)
+            state["t"] = make_thunk(*coords, interpret=interpret)
         return state["t"]()
 
     return thunk

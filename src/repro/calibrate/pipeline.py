@@ -20,14 +20,15 @@ def run_calibration(platform: str = "tpu_v5e", backend: str = "repro-jax",
                     points_per_axis: int = 3,
                     families: Optional[Sequence[str]] = None,
                     notes: str = "",
-                    axes_override: Optional[Dict] = None
-                    ) -> CalibrationArtifact:
+                    axes_override: Optional[Dict] = None,
+                    interpret: bool = False) -> CalibrationArtifact:
     """Run the full calibration pipeline and return the artifact.
 
     ``created_at`` is required provenance supplied by the caller (an
     ISO-8601 timestamp) — the pipeline never reads ambient wall-clock
     time, so the same sweep with the deterministic timer reproduces the
-    artifact byte-for-byte.
+    artifact byte-for-byte.  ``interpret`` runs the wall-clock timer's
+    Pallas kernels in interpret mode (a host with no TPU).
     """
     if not created_at:
         raise ValueError(
@@ -40,7 +41,7 @@ def run_calibration(platform: str = "tpu_v5e", backend: str = "repro-jax",
     harness = MeasurementHarness(
         platform=platform, backend=backend, timer=timer,
         points_per_axis=points_per_axis, families=families,
-        axes_override=axes_override)
+        axes_override=axes_override, interpret=interpret)
     samples = harness.measure_all()
     return CalibrationArtifact(
         platform=harness.platform.name, backend=backend,

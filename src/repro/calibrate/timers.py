@@ -3,11 +3,11 @@
 A timer maps ``(operator descriptor, kernel thunk) -> seconds``.  Two
 implementations ship:
 
-* :class:`WallClockTimer` — actually executes the kernel thunk (Pallas in
-  interpret mode on CPU, compiled on a real TPU backend) and returns a
-  median-of-trials wall-clock measurement.  This is the timer a real-TPU
-  calibration run swaps in; on this CPU container it exercises the same
-  code path through the interpreter.
+* :class:`WallClockTimer` — actually executes the kernel thunk (compiled
+  on a TPU, or through the Pallas interpreter when the harness is asked
+  for ``interpret=True``) and returns a median-of-trials wall-clock
+  measurement.  This is the timer a real-TPU calibration run swaps in; on
+  a CPU host it exercises the same code path through the interpreter.
 
 * :class:`DeterministicTimer` — the CI timer.  It never executes the
   kernel: it derives a pseudo-measurement from the analytical model with a

@@ -474,7 +474,7 @@ def cmd_calibrate_run(args) -> int:
         platform=args.platform, backend=args.backend,
         timer=make_timer(args.timer, args.platform),
         created_at=created_at, points_per_axis=args.points,
-        families=families, notes=args.notes)
+        families=families, notes=args.notes, interpret=args.interpret)
     art.save(args.out)
     report = accuracy_report(art)
     if args.json:
@@ -1270,7 +1270,10 @@ def _build_parser() -> argparse.ArgumentParser:
                     choices=["deterministic", "wallclock"],
                     help="deterministic: CI-reproducible analytical-skew "
                          "timer; wallclock: execute the real kernels "
-                         "(interpret mode on CPU, compiled on TPU)")
+                         "(compiled; add --interpret on a CPU host)")
+    cr.add_argument("--interpret", action="store_true",
+                    help="run the wallclock timer's Pallas kernels in "
+                         "interpret mode (needed where no TPU is attached)")
     cr.add_argument("--points", type=int, default=3,
                     help="measurement points per grid axis")
     cr.add_argument("--families", default="",

@@ -78,3 +78,22 @@ PLATFORMS: Dict[str, Platform] = {
 
 def get_platform(name: str) -> Platform:
     return PLATFORMS[name]
+
+
+#: ``jax.Device.device_kind`` -> platform key, for the devices this repo
+#: has a profile for.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5e": "tpu_v5e",
+    "TPU v5p": "tpu_v5p",
+}
+
+
+def platform_for_device_kind(kind: str) -> str:
+    """The platform key of a JAX device kind; an unknown kind is an error."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no platform profile for device kind {kind!r}; known kinds: "
+            f"{', '.join(sorted(DEVICE_KINDS))}") from None

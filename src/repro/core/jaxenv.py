@@ -20,6 +20,10 @@ REPRO_JAX_X64           when set truthy, enable 64-bit jax arrays before
                         the first jax pricing call
 REPRO_JAX_PLATFORM      force jax_platform_name (e.g. "cpu")
 REPRO_HOST_DEVICES      --xla_force_host_platform_device_count value
+JAX_COMPILATION_CACHE_DIR
+                        JAX's own persistent compile cache setting; where it
+                        is unset, the entry points put the cache at
+                        ``<checkout>/.jax_cache`` (:func:`use_compile_cache`)
 """
 from __future__ import annotations
 
@@ -85,6 +89,27 @@ def set_host_device_count(n: int) -> None:
             if not f.startswith("--xla_force_host_platform_device_count")]
     kept.append(f"--xla_force_host_platform_device_count={int(n)}")
     os.environ["XLA_FLAGS"] = " ".join(kept)
+
+
+#: Fixed in-checkout compile-cache path: the cache key includes the path,
+#: so a directory that moves between runs never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and
+    stands.  Otherwise the cache goes to :data:`CHECKOUT_CACHE_DIR`.
+    Called by the entry points, never at import."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def configure_from_env() -> None:

@@ -18,13 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; interpret mode runs without them
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda shape: pl.VMEM(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -111,7 +105,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
-            _SCRATCH((bq, D)), _SCRATCH((bq,)), _SCRATCH((bq,))],
+            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32)],
         interpret=interpret,
     )(qt, kt, vt)
 

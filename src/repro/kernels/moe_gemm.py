@@ -13,13 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda shape: pl.VMEM(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BC = 128
 DEFAULT_BF = 128
@@ -66,7 +60,7 @@ def moe_gemm(xe: jax.Array, we: jax.Array, *, block_c: int = DEFAULT_BC,
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, l: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, xe.shape[1], we.shape[2]), xe.dtype),
-        scratch_shapes=[_SCRATCH((bc, bf))],
+        scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=interpret,
     )(xe, we)
     if pc or pf:

@@ -1,15 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU and are validated against ref.py in interpret mode) and
-False on real TPU backends.
+The kernels target the TPU and run compiled by default.  ``interpret`` is
+an explicit argument: callers on a CPU host (the tests, a wall-clock
+calibration run without a chip) pass ``interpret=True`` themselves and get
+the Pallas interpreter, validated against ref.py.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import decode_attention as _da
@@ -17,17 +17,12 @@ from repro.kernels import moe_gemm as _mg
 from repro.kernels import rglru_scan as _rs
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = _fa.DEFAULT_BQ,
                     block_k: int = _fa.DEFAULT_BK,
-                    interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                    interpret: bool = False):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret)
@@ -36,8 +31,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, valid_len, *,
                      block_k: int = _da.DEFAULT_BK,
-                     interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                     interpret: bool = False):
     return _da.decode_attention(q, k_cache, v_cache, valid_len,
                                 block_k=block_k, interpret=interpret)
 
@@ -45,8 +39,7 @@ def decode_attention(q, k_cache, v_cache, valid_len, *,
 @functools.partial(jax.jit, static_argnames=("block_s", "block_w", "interpret"))
 def rglru_scan(a, b, h0, *, block_s: int = _rs.DEFAULT_BS,
                block_w: int = _rs.DEFAULT_BW,
-               interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+               interpret: bool = False):
     return _rs.rglru_scan(a, b, h0, block_s=block_s, block_w=block_w,
                           interpret=interpret)
 
@@ -55,7 +48,6 @@ def rglru_scan(a, b, h0, *, block_s: int = _rs.DEFAULT_BS,
                                              "interpret"))
 def moe_gemm(xe, we, *, block_c: int = _mg.DEFAULT_BC,
              block_f: int = _mg.DEFAULT_BF, block_d: int = _mg.DEFAULT_BD,
-             interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+             interpret: bool = False):
     return _mg.moe_gemm(xe, we, block_c=block_c, block_f=block_f,
                         block_d=block_d, interpret=interpret)

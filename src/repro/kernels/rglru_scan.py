@@ -17,13 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda shape: pl.VMEM(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BB = 8
 DEFAULT_BS = 128
@@ -35,20 +29,16 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bs: int):
 
     @pl.when(s == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)      # (1, bw)
 
-    a = a_ref[0].astype(jnp.float32)      # (bs, bw)
-    b = b_ref[0].astype(jnp.float32)
-    h = h_ref[...]                        # (bb=1 squeezed? no: (bb, bw))
-
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        o_ref[0, t] = h.astype(o_ref.dtype)
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + b_ref[0, row, :].astype(jnp.float32))    # (1, bw)
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, bs, step, h)
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, bs, step, h_ref[...])
 
 
 def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *,
@@ -71,19 +61,21 @@ def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *,
     ns = a.shape[1] // bs
     nw = a.shape[2] // bw
 
+    # h0 as (B, 1, W): a (1, bw) block's last two dims then meet the TPU
+    # lowering's tiling rule at any batch size.
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs),
         grid=(B, nw, ns),
         in_specs=[
             pl.BlockSpec((1, bs, bw), lambda i, w, s: (i, s, w)),
             pl.BlockSpec((1, bs, bw), lambda i, w, s: (i, s, w)),
-            pl.BlockSpec((1, bw), lambda i, w, s: (i, w)),
+            pl.BlockSpec((1, 1, bw), lambda i, w, s: (i, 0, w)),
         ],
         out_specs=pl.BlockSpec((1, bs, bw), lambda i, w, s: (i, s, w)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        scratch_shapes=[_SCRATCH((bw,))],
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0[:, None, :])
     if pad_s or pad_w:
         out = out[:, :S, :W]
     return out

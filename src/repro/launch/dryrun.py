@@ -279,7 +279,7 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool,
         fn, args, in_sh, out_sh = build_step(cfg, shape, mesh)
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
         t0 = time.perf_counter()
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
             res.lower_s = time.perf_counter() - t0
             t1 = time.perf_counter()

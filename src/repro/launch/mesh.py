@@ -7,6 +7,14 @@ XLA flag, and only before its first jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code pins
+    layouts with bare ``PartitionSpec`` constraints, which JAX accepts only
+    on Auto axes (its default is now Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, expert_axis: int = 0):
@@ -19,15 +27,15 @@ def make_production_mesh(*, multi_pod: bool = False, expert_axis: int = 0):
     if expert_axis:
         assert 16 % expert_axis == 0
         if multi_pod:
-            return jax.make_mesh((2, 16, expert_axis, 16 // expert_axis),
-                                 ("pod", "data", "expert", "model"))
-        return jax.make_mesh((16, expert_axis, 16 // expert_axis),
-                             ("data", "expert", "model"))
+            return _auto_mesh((2, 16, expert_axis, 16 // expert_axis),
+                              ("pod", "data", "expert", "model"))
+        return _auto_mesh((16, expert_axis, 16 // expert_axis),
+                          ("data", "expert", "model"))
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the real local device (CPU smoke runs)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

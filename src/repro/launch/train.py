@@ -18,6 +18,7 @@ import numpy as np
 
 from repro import models
 from repro.configs import get_config
+from repro.core.jaxenv import use_compile_cache
 from repro.training import checkpoint as ckpt
 from repro.training import data as dat
 from repro.training import optimizer as opt
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--full-config", action="store_true",
                     help="use the full (not reduced) config — TPU scale")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

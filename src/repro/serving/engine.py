@@ -72,6 +72,9 @@ class Engine:
             functools.partial(models.decode_step, cfg=self.cfg),
             static_argnames=(), donate_argnames=("cache",))
         self._prefill_cache: Dict[int, Any] = {}
+        # device logits of the latest step: {"prefill": {rid: (V,)},
+        # "decode": (B, V) by slot or None} — for checks against a reference
+        self.step_logits: Dict[str, Any] = {"prefill": {}, "decode": None}
 
     # ------------------------------------------------------------------
     def _prefill_fn(self, isl: int):
@@ -93,7 +96,8 @@ class Engine:
         self.cache["k"] = self.cache["k"].at[:, slot].set(cache["k"][:, 0])
         self.cache["v"] = self.cache["v"].at[:, slot].set(cache["v"][:, 0])
         self._pos_host[slot] = req.isl
-        tok = int(jnp.argmax(logits[0, -1]))
+        last = self.step_logits["prefill"][req.rid] = logits[0, -1]
+        tok = int(jnp.argmax(last))
         self._last_tok[slot] = tok
         req.out_tokens.append(tok)
         return tok
@@ -104,7 +108,8 @@ class Engine:
         logits, self.cache = self._decode_fn(
             params=self.params, token=tokens, cache=self.cache)
         logits.block_until_ready()
-        new = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        last = self.step_logits["decode"] = logits[:, -1]
+        new = np.asarray(jnp.argmax(last, axis=-1), np.int32)
         for req in active:
             self._pos_host[req.slot] += 1
             self._last_tok[req.slot] = new[req.slot]
@@ -117,6 +122,7 @@ class Engine:
         plan = self.sched.plan(now)
         if plan.empty:
             return []
+        self.step_logits = {"prefill": {}, "decode": None}
         for chunk in plan.prefill:     # whole prompts (chunked=False)
             self._run_prefill(chunk.req)
         if plan.decode:

@@ -96,7 +96,7 @@ def test_wallclock_timer_times_the_real_kernels():
     art = run_calibration(
         "tpu_v5e", "repro-jax", timer=WallClockTimer(reps=1, trials=1),
         created_at=CREATED, points_per_axis=2,
-        families=["gemm"], axes_override=TINY_AXES)
+        families=["gemm"], axes_override=TINY_AXES, interpret=True)
     assert all(s.measured_s > 0 for s in art.samples)
     assert art.timer == "wallclock"
     fit = art.fits["gemm"]
@@ -110,7 +110,8 @@ def test_wallclock_full_pipeline_all_families():
     improves (or at worst matches) the per-family MAPE."""
     art = run_calibration(
         "tpu_v5e", "repro-jax", timer=WallClockTimer(reps=1, trials=1),
-        created_at=CREATED, points_per_axis=2, axes_override=TINY_AXES)
+        created_at=CREATED, points_per_axis=2, axes_override=TINY_AXES,
+        interpret=True)
     assert set(art.fits) == set(MEASURED_FAMILIES)
     assert CalibrationArtifact.from_json(art.to_json()) == art
     rep = accuracy_report(art)

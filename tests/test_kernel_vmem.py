@@ -25,10 +25,11 @@ def test_flash_attention_vmem():
 
 def test_decode_attention_vmem():
     from repro.kernels.decode_attention import DEFAULT_BK
-    G, D = 16, 128
+    # one block holds every KV head: qwen3-32b's K=8 heads, G=8 each, D=128
+    K, G, D = 8, 8, 128
     working = (
-        G * D * 2 + 2 * DEFAULT_BK * D * 2
-        + G * D * 4 + 2 * G * 4 + G * DEFAULT_BK * 4
+        K * G * D * 2 + 2 * DEFAULT_BK * K * D * 2
+        + K * G * D * 4 + 2 * K * G * 4 + K * G * DEFAULT_BK * 4
     ) * DOUBLE_BUFFER
     assert working < VMEM_BUDGET, working
 
