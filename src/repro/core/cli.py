@@ -63,6 +63,7 @@ from repro.configs import list_archs
 from repro.core.backends.base import all_backends, backend_capabilities
 from repro.core.generator import generate
 from repro.core.hardware import PLATFORMS
+from repro.obs.capture import ObsCapture
 
 EXIT_OK = 0
 EXIT_NO_CONFIG = 1
@@ -250,67 +251,10 @@ class _JsonLines:
             return False
 
 
-class _ObsCapture:
-    """``--trace-out``/``--metrics-out``: install a ``repro.obs`` tracer
-    and/or metrics registry for the duration of the command, then write
-    the artifacts on the way out (``finish``).  The trace artifact keeps
-    wall times out, so seeded runs write byte-identical files;
-    ``--trace-out -`` streams the JSONL to stdout, a ``.chrome.json``
-    suffix writes the Chrome ``trace_event`` export instead (load it in
-    chrome://tracing or Perfetto).  The flight-recorder sampling knobs
-    (``--span-sample-every``/``--max-request-spans``) bound per-request
-    span volume on big traces."""
-
-    def __init__(self, args):
-        self.trace_out = getattr(args, "trace_out", "")
-        self.metrics_out = getattr(args, "metrics_out", "")
-        self.meta = {"command": getattr(args, "command", None) or "search",
-                     "model": getattr(args, "model", None)}
-        self.tracer = self.registry = None
-        self._flight_restore = None
-        sample = getattr(args, "span_sample_every", None)
-        cap = getattr(args, "max_request_spans", None)
-        if sample is not None or cap is not None:
-            from repro.obs import configure_flight_recorder, flight_config
-            prev = flight_config()
-            self._flight_restore = (prev.sample_every,
-                                    prev.max_request_spans)
-            configure_flight_recorder(
-                sample_every=sample if sample is not None else 1,
-                max_request_spans=cap if cap is not None else 512)
-        if self.trace_out:
-            from repro.obs import enable_tracing
-            self.tracer = enable_tracing()
-        if self.metrics_out:
-            from repro.obs import enable_metrics
-            self.registry = enable_metrics()
-
-    def finish(self) -> None:
-        if self._flight_restore is not None:
-            from repro.obs import configure_flight_recorder
-            configure_flight_recorder(*self._flight_restore)
-        if self.tracer is not None:
-            from repro.obs import disable_tracing
-            disable_tracing()
-            art = self.tracer.artifact(meta=self.meta)
-            if self.trace_out == "-":
-                _JsonLines().emit_text(art.to_jsonl())
-            elif self.trace_out.endswith(".chrome.json"):
-                with open(self.trace_out, "w") as f:
-                    f.write(json.dumps(art.to_chrome_trace(),
-                                       sort_keys=True) + "\n")
-            else:
-                art.save(self.trace_out)
-        if self.registry is not None:
-            from repro.obs import disable_metrics
-            disable_metrics()
-            if self.metrics_out.endswith(".prom"):
-                text = self.registry.to_prometheus()
-            else:
-                text = json.dumps(self.registry.to_dict(), indent=2,
-                                  sort_keys=True) + "\n"
-            with open(self.metrics_out, "w") as f:
-                f.write(text)
+def _obs_capture(args) -> ObsCapture:
+    """The ``repro.obs`` capture of one command; ``--trace-out -``
+    streams through the BrokenPipe-safe emitter."""
+    return ObsCapture(args, emit_text=_JsonLines().emit_text)
 
 
 def _stream_search(args) -> int:
@@ -374,7 +318,7 @@ def _stream_search(args) -> int:
 
 
 def cmd_search(args) -> int:
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         if args.stream:
             return _stream_search(args)
@@ -653,7 +597,7 @@ def cmd_workload_replay(args) -> int:
     runner = TaskRunner(w)
     sim = runner.simulator(cand, priority_admission=True,
                            max_queue=args.max_queue)
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         metrics = sim.replay(trace, slo=_slo_from_args(args),
                              max_steps=args.max_steps)
@@ -710,7 +654,7 @@ def cmd_capacity_sweep(args) -> int:
     best = None
     records = []
     em = _JsonLines()
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         for rec in iter_ladder(runner, [cand], trace, _slo_from_args(args),
                                ladder=ladder, routing=args.routing,
@@ -781,7 +725,7 @@ def cmd_capacity_plan(args) -> int:
     """Search, then size the deployment: analytical top-K × ladder →
     min-chip plan, recorded in the schema-v4 SearchReport."""
     cfg = _configurator(args)
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         report = cfg.plan_capacity(
             args.trace, _slo_from_args(args),
@@ -867,7 +811,7 @@ def cmd_autoscale_run(args) -> int:
         cand, policy, routing=args.routing,
         initial_replicas=args.initial_replicas, tick_s=args.tick,
         cold_start_s=args.cold_start, max_queue=args.max_queue)
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         report = sim.run(trace, slo=_slo_from_args(args),
                          max_steps=args.max_steps)
@@ -913,7 +857,7 @@ def cmd_autoscale_compare(args) -> int:
         args, trace,
         n_chips=args.tp * args.pp * max(max(ladder), policy.max_replicas))
     runner = TaskRunner(w)
-    obs = _ObsCapture(args)
+    obs = _obs_capture(args)
     try:
         section, run = build_autoscale_section(
             runner, cand, trace, _slo_from_args(args), policy,

@@ -17,6 +17,14 @@ with tp·pp·ep·dp > 1, or a disaggregated one, is refused (exit 2).
 
 :func:`serve` is the callable form: it takes the parsed arguments and
 returns the measurements, so a script can run it in its own process.
+
+Observability: ``--trace-out PATH`` writes the engine's ``repro.obs`` spans
+(``engine.step`` and its phases, on the wall clock; ``.chrome.json`` for
+the Chrome export), ``--metrics-out PATH`` its ``repro_engine_*`` counters
+(JSON, or Prometheus text with ``.prom``), and ``--profile-dir DIR`` a
+``jax.profiler`` trace of the timed window with the engine's spans on its
+host plane, beside the device's programs (open it in TensorBoard or
+Perfetto).
 """
 from __future__ import annotations
 
@@ -33,6 +41,7 @@ import numpy as np
 from repro import models
 from repro.configs import get_config
 from repro.core.jaxenv import use_compile_cache
+from repro.obs import ObsCapture, Tracer
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.request import Request
 
@@ -67,6 +76,17 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--isl", type=int, default=16)
     ap.add_argument("--osl", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-out", default="", metavar="PATH",
+                    help="write the engine's repro.obs spans here (JSONL "
+                         "TraceArtifact on the wall clock; '-' streams it "
+                         "to stdout; a .chrome.json suffix writes the "
+                         "Chrome trace_event export)")
+    ap.add_argument("--metrics-out", default="", metavar="PATH",
+                    help="write the engine's repro_engine_* counters here "
+                         "(JSON, or Prometheus text with a .prom suffix)")
+    ap.add_argument("--profile-dir", default="", metavar="DIR",
+                    help="capture a jax.profiler trace of the timed window "
+                         "here, with the engine's spans on its host plane")
     return ap.parse_args(argv)
 
 
@@ -136,12 +156,17 @@ def serve(args: argparse.Namespace, launch: Optional[Dict] = None,
     eng.run_until_drained()
     compile_s = time.perf_counter() - t0
 
+    profile_dir = getattr(args, "profile_dir", "")
+    if profile_dir:
+        jax.profiler.start_trace(profile_dir)
     t_start = time.perf_counter()
     for i, prompt in enumerate(prompts):
         eng.add_request(Request(rid=i, isl=len(prompt), osl=args.osl,
                                 arrival=time.perf_counter(), prompt=prompt))
     done = eng.run_until_drained()
     wall = time.perf_counter() - t_start
+    if profile_dir:
+        jax.profiler.stop_trace()
 
     ttfts = [r.ttft for r in done if r.ttft is not None]
     tpots = [r.tpot for r in done if r.tpot is not None]
@@ -166,11 +191,17 @@ def serve(args: argparse.Namespace, launch: Optional[Dict] = None,
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = parse_args(argv)
     use_compile_cache()
+    tracer = None
+    if args.trace_out or args.profile_dir:
+        tracer = Tracer(timebase="wall", profiler=bool(args.profile_dir))
+    obs = ObsCapture(args, command="serve", tracer=tracer)
     try:
         m = serve(args)
     except UnservableLaunch as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    finally:
+        obs.finish()
     d = m["device"]
     print(f"served {m['requests']} requests ({m['model']}, {m['slots']} "
           f"slots) in {m['wall_s']:.2f}s on {d['platform']} {d['kind']}")

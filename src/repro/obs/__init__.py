@@ -15,6 +15,9 @@ Three layers, all zero-cost until installed:
   per-request lifecycle spans (arrival → queued → prefill → decode)
   and fixed log2-bucket latency histograms, emitted by every replay
   simulator and sampled through ``configure_flight_recorder``.
+* :mod:`repro.obs.capture` — ``ObsCapture``, the ``--trace-out``/
+  ``--metrics-out`` capture that the configurator's CLI and the serve
+  entry point share.
 * :mod:`repro.obs.diff` — telemetry diffing: counter/gauge deltas and
   per-histogram distribution shifts between two snapshots (surfaced as
   the ``obs diff`` CLI subcommand).
@@ -28,10 +31,11 @@ Three layers, all zero-cost until installed:
   serving phase, and a two-candidate diff (surfaced as
   ``Configurator.explain`` and the ``explain`` CLI subcommand).
 
-``trace``/``metrics``/``flight``/``diff`` are import-light (stdlib
+``trace``/``metrics``/``flight``/``diff``/``capture`` are import-light (stdlib
 only); ``explain`` pulls in the pricing stack and loads lazily so the
 core modules can import this package without a cycle.
 """
+from repro.obs.capture import ObsCapture
 from repro.obs.diff import diff_metrics, format_diff, load_metrics_snapshot
 from repro.obs.flight import (FlightRecorderConfig,
                               configure_flight_recorder,
@@ -57,7 +61,7 @@ _BENCH_NAMES = ("BenchArtifact", "BenchRecord", "BenchTiming",
 
 __all__ = [
     "FlightRecorderConfig", "LATENCY_MS_BUCKETS", "MetricsRegistry",
-    "NULL_TRACER", "NullTracer", "SpanRecord",
+    "NULL_TRACER", "NullTracer", "ObsCapture", "SpanRecord",
     "SUPPORTED_TRACE_SCHEMA_VERSIONS", "TRACE_SCHEMA_VERSION",
     "TraceArtifact", "Tracer", "configure_flight_recorder",
     "diff_metrics", "disable_metrics", "disable_tracing",

@@ -77,12 +77,14 @@ def histogram_quantile(buckets: Sequence[float], counts: Sequence[int],
     """Estimate the p-quantile (p in [0, 1]) of a bucketed histogram.
 
     ``counts`` has ``len(buckets) + 1`` entries (the last is the +Inf
-    overflow).  The estimator locates the bucket holding the sample at
-    rank ``p * (count - 1)`` — the same rank convention as the exact
-    :func:`repro.serving.sim.percentile` — and interpolates linearly
-    inside it, so the estimate always lands within one bucket of the
-    exact sample percentile.  The first bucket interpolates from 0, the
-    overflow bucket clamps to the last finite edge.  Empty histograms
+    overflow).  The rank is ``p * (count - 1)``, the convention of the
+    exact :func:`repro.serving.sim.percentile`: the samples at
+    ``floor(rank)`` and ``ceil(rank)`` are each placed inside their own
+    bucket (the j-th of a bucket's c samples at ``(j + 0.5) / c`` of its
+    width), and the estimate interpolates between them by the rank's
+    fraction.  So it is monotone in ``p`` and lands within one bucket of
+    the exact sample percentile.  The first bucket interpolates from 0,
+    the overflow bucket clamps to the last finite edge.  Empty histograms
     return None (never NaN).
     """
     if not 0.0 <= p <= 1.0:
@@ -93,17 +95,24 @@ def histogram_quantile(buckets: Sequence[float], counts: Sequence[int],
     total = sum(counts)
     if total == 0:
         return None
+
+    def sample(k: int) -> float:
+        cum = 0
+        for i, c in enumerate(counts):
+            if cum + c > k:
+                if i >= len(buckets):      # overflow: clamp, no far edge
+                    return float(buckets[-1])
+                lo = float(buckets[i - 1]) if i > 0 else 0.0
+                hi = float(buckets[i])
+                return lo + (hi - lo) * ((k - cum + 0.5) / c)
+            cum += c
+        return float(buckets[-1])
+
     rank = p * (total - 1)
-    cum = 0
-    for i, c in enumerate(counts):
-        if cum + c > rank:
-            if i >= len(buckets):          # overflow: clamp, no far edge
-                return float(buckets[-1])
-            lo = float(buckets[i - 1]) if i > 0 else 0.0
-            hi = float(buckets[i])
-            return lo + (hi - lo) * ((rank - cum + 0.5) / c)
-        cum += c
-    return float(buckets[-1])
+    below = math.floor(rank)
+    a = sample(below)
+    frac = rank - below
+    return a if frac == 0 else a + (sample(below + 1) - a) * frac
 
 
 def _fmt(v: float) -> str:

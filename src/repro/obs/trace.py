@@ -13,6 +13,14 @@ A :class:`Tracer` records nested spans opened with
   bytes unless explicitly requested, because they are the one
   non-deterministic thing a trace holds.
 
+A tracer built with ``timebase="wall"`` snapshots its own clock instead,
+as seconds since the tracer was made, so a span around real work (the
+serving engine's steps) records when it ran and its Chrome export shows
+the served timeline.  With ``profiler=True`` every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, which lands on the
+profiler trace's ``/host:CPU`` plane on the device trace's clock; jax is
+imported only then, so this module stays import-light.
+
 The default tracer is :data:`NULL_TRACER`: ``span()`` hands back a shared
 no-op context manager, so instrumentation in the pricing hot paths costs
 one attribute call when tracing is off and never perturbs results.
@@ -224,7 +232,7 @@ class TraceArtifact:
 class Span:
     """A live span; also its own context manager."""
     __slots__ = ("name", "seq", "parent", "depth", "attrs",
-                 "v_start", "v_end", "wall_s", "_tracer", "_t0")
+                 "v_start", "v_end", "wall_s", "_tracer", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict):
         self.name = name
@@ -237,6 +245,7 @@ class Span:
         self.v_end = 0.0
         self.wall_s = 0.0
         self._t0 = 0.0
+        self._note = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes mid-span (e.g. counts known only at exit)."""
@@ -248,16 +257,25 @@ class Span:
         self.seq = len(t.spans)
         self.parent = t._stack[-1].seq if t._stack else None
         self.depth = len(t._stack)
-        self.v_start = t.virtual_time
         t.spans.append(self)
         t._stack.append(self)
+        if t._annotation is not None:
+            self._note = t._annotation(self.name)
+            self._note.__enter__()
         self._t0 = t._clock()
+        self.v_start = (self._t0 - t._origin if t._origin is not None
+                        else t.virtual_time)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t = self._tracer
-        self.wall_s = t._clock() - self._t0
-        self.v_end = t.virtual_time
+        end = t._clock()
+        self.wall_s = end - self._t0
+        self.v_end = (end - t._origin if t._origin is not None
+                      else t.virtual_time)
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+            self._note = None
         if t._stack and t._stack[-1] is self:
             t._stack.pop()
         else:                       # tolerate mis-nested exits
@@ -273,18 +291,32 @@ class Span:
 
 
 class Tracer:
-    """Collects nested spans against a virtual + wallclock timebase."""
+    """Collects nested spans against a virtual + wallclock timebase.
+
+    ``timebase="wall"`` makes a span's ``v_start``/``v_end`` read the
+    tracer's clock (seconds since the tracer was made) instead of
+    ``virtual_time``; ``profiler=True`` bridges every span to a
+    ``jax.profiler.TraceAnnotation`` of the same name."""
 
     #: real tracers record spans; the flight recorder checks this one
     #: attribute before materializing per-request span payloads, so
     #: replays under :data:`NULL_TRACER` build nothing at all
     records_spans = True
 
-    def __init__(self, clock=time.perf_counter):
+    def __init__(self, clock=time.perf_counter, timebase: str = "virtual",
+                 profiler: bool = False):
+        if timebase not in ("virtual", "wall"):
+            raise ValueError(f"timebase must be 'virtual' or 'wall', "
+                             f"got {timebase!r}")
         self._clock = clock
         self.spans: List[Span] = []
         self._stack: List[Span] = []
         self.virtual_time = 0.0     # simulators advance this (sim seconds)
+        self._origin = clock() if timebase == "wall" else None
+        self._annotation = None
+        if profiler:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
 
     def span(self, name: str, **attrs) -> Span:
         return Span(self, name, attrs)

@@ -347,3 +347,79 @@ def test_enable_disable_metrics_round_trip():
     assert get_metrics() is None
     mine = MetricsRegistry()
     assert enable_metrics(mine) is mine and get_metrics() is mine
+
+
+# ---------------------------------------------------------------------------
+# wall timebase, profiler bridge, and the shared CLI capture
+# ---------------------------------------------------------------------------
+
+def test_wall_timebase_spans_read_the_tracers_clock():
+    tr = Tracer(clock=_fake_clock(), timebase="wall")   # origin at 1 ms
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    outer, inner = tr.artifact().spans
+    assert (outer.v_start, inner.v_start) == pytest.approx((1e-3, 2e-3))
+    assert (inner.v_end, outer.v_end) == pytest.approx((3e-3, 4e-3))
+    tr.virtual_time = 99.0                  # ignored on the wall clock
+    with tr.span("late") as sp:
+        pass
+    assert sp.v_start == pytest.approx(5e-3)
+
+
+def test_unknown_timebase_is_refused():
+    with pytest.raises(ValueError, match="timebase"):
+        Tracer(timebase="device")
+
+
+def test_profiler_bridge_enters_one_annotation_per_span(monkeypatch):
+    import jax
+    log = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Note)
+    tr = Tracer(timebase="wall", profiler=True)
+    with tr.span("engine.step"):
+        with tr.span("engine.decode", active=3):
+            pass
+    assert log == [("enter", "engine.step"), ("enter", "engine.decode"),
+                   ("exit", "engine.decode"), ("exit", "engine.step")]
+    assert [s.name for s in tr.artifact().spans] == ["engine.step",
+                                                     "engine.decode"]
+    plain = Tracer()
+    with plain.span("x"):
+        pass
+    assert len(log) == 4                    # no bridge unless asked
+
+
+def test_obs_capture_lives_in_repro_obs(tmp_path):
+    import argparse
+    from repro.core import cli
+    from repro.obs import ObsCapture
+    assert not hasattr(cli, "ObsCapture") or cli.ObsCapture is ObsCapture
+    assert not hasattr(cli, "_ObsCapture")
+    args = argparse.Namespace(trace_out="", metrics_out="")
+    tr = Tracer(timebase="wall")
+    cap = ObsCapture(args, command="serve", tracer=tr)
+    assert get_tracer() is tr and get_metrics() is None
+    with get_tracer().span("engine.step"):
+        pass
+    cap.finish()
+    assert get_tracer() is NULL_TRACER and not list(tmp_path.iterdir())
+    out = tmp_path / "t.jsonl"
+    cap = ObsCapture(argparse.Namespace(trace_out=str(out), metrics_out=""),
+                     command="serve", tracer=Tracer(timebase="wall"))
+    with get_tracer().span("engine.step"):
+        pass
+    cap.finish()
+    art = TraceArtifact.load(str(out))
+    assert art.meta == {"command": "serve", "model": None}
+    assert [s.name for s in art.spans] == ["engine.step"]
