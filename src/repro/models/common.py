@@ -518,6 +518,71 @@ def decode_pos_vec(pos: jax.Array, batch: int) -> jax.Array:
     return jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (batch, 1)).astype(jnp.int32)
 
 
+def decode_layers(cfg: ModelConfig, x: jax.Array, layers: Dict,
+                  cache: Dict, ffn: Callable[[Dict, jax.Array], jax.Array],
+                  mrope_positions: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, Dict]:
+    """The decode step's layer loop against a stacked ring-buffer cache.
+
+    x: (B, 1, d) embedded tokens; layers: the stacked layer params; cache:
+    ``k``/``v`` (L, B, W, K, D), with ``k_scale``/``v_scale`` (L, B, W, K)
+    when they hold int8, and ``pos``, () uniform or (B,) per row.
+    ``ffn(lp, h)`` is the layer's FFN on the normed residual ``h``.
+    Returns (x, the cache's arrays).
+
+    The stacked caches ride in the scan's carry with the layer index, not
+    through its xs/ys: layer ``l`` writes its new token, one per row, at
+    ``(l, row, pos % W)`` of the carried stack and attends over layer
+    ``l`` read back from it.  A step writes one token per row and layer
+    and never copies the cache, so a caller that donates the cache gets it
+    updated in place.
+    """
+    B = x.shape[0]
+    pos = cache["pos"]
+    W = cache["k"].shape[2]
+    positions = decode_pos_vec(pos, B)
+    valid_len = jnp.minimum(pos + 1, W)
+    slot = pos % W
+    quant = "k_scale" in cache
+    names = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+
+    def write(stack, l, new):
+        """new: (B, 1, ...) -> stack[l, row, slot[row]]."""
+        if pos.ndim == 0:
+            start = (l, 0, slot) + (0,) * (stack.ndim - 3)
+            return lax.dynamic_update_slice(stack, new[None], start)
+        return stack.at[l, jnp.arange(B), slot].set(new[:, 0])
+
+    def body(carry, lp):
+        y, stacks, l = carry
+        h = rms_norm(y, lp["norm0"], cfg.norm_eps)
+        q, k, v = qkv_project(lp, h, cfg, positions,
+                              mrope_positions=mrope_positions)
+        if quant:
+            (k, k_s), (v, v_s) = kv_quantize(k), kv_quantize(v)
+            new = {"k": k, "v": v, "k_scale": k_s, "v_scale": v_s}
+        else:
+            new = {"k": k, "v": v}
+        stacks = {n: write(stacks[n], l, new[n]) for n in names}
+        view = {n: lax.dynamic_index_in_dim(stacks[n], l, keepdims=False)
+                for n in names}
+        if quant:
+            k_l = kv_dequantize(view["k"], view["k_scale"], y.dtype)
+            v_l = kv_dequantize(view["v"], view["v_scale"], y.dtype)
+        else:
+            k_l, v_l = view["k"], view["v"]
+        attn = decode_attention(q, k_l, v_l, valid_len,
+                                pin=cfg.sharding.decode_attn_pin,
+                                seq_shard=cfg.sharding.shard_kv_seq)
+        y = y + jnp.einsum("bse,ed->bsd", attn.reshape(B, 1, -1), lp["wo"])
+        y = y + ffn(lp, rms_norm(y, lp["norm1"], cfg.norm_eps))
+        return (y, stacks, l + 1), None
+
+    (x, stacks, _), _ = lax.scan(
+        body, (x, {n: cache[n] for n in names}, jnp.int32(0)), layers)
+    return x, stacks
+
+
 # ---------------------------------------------------------------------------
 # Attention block parameter schema (shared by all transformer families)
 # ---------------------------------------------------------------------------

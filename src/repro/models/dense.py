@@ -6,7 +6,8 @@ embeddings).  One schema + three entry points:
 
   ``forward_train``  full causal forward -> logits (or loss via train_step)
   ``prefill``        forward + KV-cache write-out (ring-buffer layout)
-  ``decode_step``    ONE token against a cache of ``seq_len`` (ring buffer)
+  ``decode_step``    ONE token against a cache of ``seq_len`` (ring buffer),
+                     written in place: one token per row, no cache copy
 """
 from __future__ import annotations
 
@@ -147,54 +148,19 @@ def prefill(params: Dict, cfg: ModelConfig, tokens: jax.Array,
 
 def decode_step(params: Dict, cfg: ModelConfig, token: jax.Array, cache: Dict,
                 mrope_positions: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
-    """One decode step.  token: (B, 1) int32.  Returns (logits, new cache)."""
-    B = token.shape[0]
-    pos = cache["pos"]
-    W = cache["k"].shape[2]
+    """One decode step.  token: (B, 1) int32.  Returns (logits, new cache).
+
+    Each layer writes the new token's K/V, one per row at ``pos % W``, into
+    the stacked cache carried through the layer loop
+    (``common.decode_layers``); the step never copies the cache, bf16 or
+    int8 with scales.
+    """
     x = jnp.take(params["embed"]["tok_embed"], token, axis=0)  # (B,1,d)
-    positions = cm.decode_pos_vec(pos, B)
-    valid_len = jnp.minimum(pos + 1, W)
 
-    quant = cfg.sharding.kv_quant
+    def ffn(lp, h):
+        return cm.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
 
-    def body(carry, inp):
-        y = carry
-        if quant:
-            lp, kc, vc, kc_s, vc_s = inp
-        else:
-            lp, kc, vc = inp
-            kc_s = vc_s = None
-        h = cm.rms_norm(y, lp["norm0"], cfg.norm_eps)
-        q, k, v = cm.qkv_project(lp, h, cfg, positions,
+    x, stacks = cm.decode_layers(cfg, x, params["layers"], cache, ffn,
                                  mrope_positions=mrope_positions)
-        if quant:
-            kq, kq_s = cm.kv_quantize(k)
-            vq, vq_s = cm.kv_quantize(v)
-            kc, vc = cm.cache_update(kc, vc, kq, vq, pos)
-            kc_s, vc_s = cm.cache_update(
-                kc_s[..., None], vc_s[..., None],
-                kq_s[..., None], vq_s[..., None], pos)
-            kc_s, vc_s = kc_s[..., 0], vc_s[..., 0]
-            k_full = cm.kv_dequantize(kc, kc_s, y.dtype)
-            v_full = cm.kv_dequantize(vc, vc_s, y.dtype)
-        else:
-            kc, vc = cm.cache_update(kc, vc, k, v, pos)
-            k_full, v_full = kc, vc
-        attn = cm.decode_attention(q, k_full, v_full, valid_len,
-                                   pin=cfg.sharding.decode_attn_pin,
-                                   seq_shard=cfg.sharding.shard_kv_seq)
-        y = y + jnp.einsum("bse,ed->bsd", attn.reshape(B, 1, -1), lp["wo"])
-        h = cm.rms_norm(y, lp["norm1"], cfg.norm_eps)
-        y = y + cm.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return y, ((kc, vc, kc_s, vc_s) if quant else (kc, vc))
-
-    if quant:
-        x, (ks, vs, ks_s, vs_s) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        logits = cm.lm_logits(params["embed"], x, cfg)
-        return logits, {"k": ks, "v": vs, "k_scale": ks_s, "v_scale": vs_s,
-                        "pos": pos + 1}
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
     logits = cm.lm_logits(params["embed"], x, cfg)
-    return logits, {"k": ks, "v": vs, "pos": pos + 1}
+    return logits, {**stacks, "pos": cache["pos"] + 1}

@@ -222,28 +222,20 @@ def prefill(params: Dict, cfg: ModelConfig, tokens: jax.Array, max_len: int,
 
 def decode_step(params: Dict, cfg: ModelConfig, token: jax.Array, cache: Dict,
                 **_) -> Tuple[jax.Array, Dict]:
-    B = token.shape[0]
-    pos, W = cache["pos"], cache["k"].shape[2]
-    x = jnp.take(params["embed"]["tok_embed"], token, axis=0)
-    positions = cm.decode_pos_vec(pos, B)
-    valid_len = jnp.minimum(pos + 1, W)
+    """One decode step.  token: (B, 1) int32.  Returns (logits, new cache).
 
-    def body(carry, inp):
-        y = carry
-        lp, kc, vc = inp
-        h = cm.rms_norm(y, lp["norm0"], cfg.norm_eps)
-        q, k, v = cm.qkv_project(lp, h, cfg, positions)
-        kc, vc = cm.cache_update(kc, vc, k, v, pos)
-        attn = cm.decode_attention(q, kc, vc, valid_len,
-                                   pin=cfg.sharding.decode_attn_pin,
-                                   seq_shard=cfg.sharding.shard_kv_seq)
-        y = y + jnp.einsum("bse,ed->bsd", attn.reshape(B, 1, -1), lp["wo"])
-        h = cm.rms_norm(y, lp["norm1"], cfg.norm_eps)
+    Each layer writes the new token's K/V, one per row at ``pos % W``, into
+    the stacked cache carried through the layer loop
+    (``common.decode_layers``); the step never copies the cache.
+    """
+    x = jnp.take(params["embed"]["tok_embed"], token, axis=0)
+
+    def ffn(lp, h):
         mo, _ = moe_ffn(cfg, lp, h)
         if cfg.n_shared_experts:
             mo = mo + cm.swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-        return y + mo, (kc, vc)
+        return mo
 
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    x, stacks = cm.decode_layers(cfg, x, params["layers"], cache, ffn)
     logits = cm.lm_logits(params["embed"], x, cfg)
-    return logits, {"k": ks, "v": vs, "pos": pos + 1}
+    return logits, {**stacks, "pos": cache["pos"] + 1}
