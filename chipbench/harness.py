@@ -50,6 +50,9 @@ class Run:
     shapes: object = None            # counts.Shapes
     window: object = None            # serving.Window
     reduced: object = None           # trace.Reduced (``--trace 1`` only)
+    spans: object = None             # spans.Spans (``--trace 1`` only)
+    counters: object = None          # the engine's repro.obs
+                                     # MetricsRegistry (``--trace 1`` only)
 
 
 def use_compile_cache() -> str:
@@ -86,23 +89,27 @@ def emit(result: Dict, checks: Dict) -> None:
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              t_start: float, devices: Optional[list] = None,
-             check_chip: Callable = None, control: bool = False) -> Dict:
+             check_chip: Callable = None, control: bool = False,
+             on_trace: Callable = None) -> Dict:
     """Set up, measure and check one run; returns the result line's
     fields (``result``), the numbers compared (``checks``: each limit of
-    the configuration's ``limits``, and no request left unfinished) and
+    the configuration's ``limits``, and no request left unfinished),
     what each side read against the reference (``readings``,
-    ``reference.numbers``).  ``check_chip``
+    ``reference.numbers``) and what the metric readers read (``run``).
+    ``check_chip``
     (``device.check``) refuses a run without the chip; a test that drives
     the rest of a run on the CPU passes a stand-in.  ``control`` puts the
     int8 control in the program's place in the comparison: the same
     sample, the same limit, the control's first choices compared.
+    ``on_trace`` is handed a traced run's profile directory before it is
+    removed.
     """
     import jax
     from chipbench import counts, device
     devices = jax.devices() if devices is None else devices
     peaks = (check_chip or device.check)(devices, cell.chips)
     shapes = counts.Shapes.from_config(cell.config)
-    from chipbench import reference, serving, traffic
+    from chipbench import reference, serving, spans, traffic
     from chipbench import trace as tmod
     from chipbench import weights as wmod
     sv = serving.Serving(cell, seed)
@@ -111,11 +118,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     w = sv.window(seconds, trace)
     dev = device.describe(devices, cell.chips)
     run = Run(cell=cell, setup_s=setup_s, peaks=peaks, shapes=shapes,
-              window=w)
+              window=w, counters=w.counters)
     result: Dict = {}
     if w.trace_dir is not None:
-        run.reduced = tmod.load(w.trace_dir, cell.chips)
+        if on_trace is not None:
+            on_trace(w.trace_dir)
+        prof = tmod.profile(w.trace_dir)
         shutil.rmtree(w.trace_dir, ignore_errors=True)
+        run.reduced = tmod.reduce(prof, cell.chips)
+        run.spans = spans.reduce(prof)
         dev["busy_s"] = run.reduced.busy_s
         dev["window_s"] = run.reduced.window_s
         result["breakdown"] = tmod.breakdown(run.reduced)
@@ -143,7 +154,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                                  for c in checks.values())
     result.update(correct=correct, attempted=len(w.recs), failed=failed,
                   metrics=metrics, device=dev)
-    return {"result": result, "checks": checks, "readings": readings}
+    return {"result": result, "checks": checks, "readings": readings,
+            "run": run}
 
 
 def main(argv=None, t_start: Optional[float] = None) -> int:

@@ -1,10 +1,18 @@
-"""Serving cells: the program's engine under open-loop traffic.
+"""Serving cells: the program's engine under open- or closed-loop traffic.
 
 The window drives ``Engine.add_request`` / ``Engine.step`` and nothing
 else, from one thread.  Timestamps are the host clock after each
 ``step()`` returns: a token is delivered when the step that made it ends.
-Requests are due at the mix's arrival times, and each is timed from when
+In an open loop requests are due at the mix's arrival times; in a closed
+loop each client's next request is due when it is sent, in the engine
+step after its previous one finished.  Each request is timed from when
 it was due, so a stall counts against every request waiting behind it.
+
+A traced window (``--trace 1``) runs the engine under a wall-clock
+``repro.obs`` tracer bridged to the profiler, so its ``engine.*`` spans
+land on the trace, and counts into a fresh metrics registry
+(``Window.counters``); both are installed after warm-up and removed
+after the drain.  An untraced window installs neither.
 
 Every request due in the window is served: after the close the loop
 steps on until each has finished, up to ``DRAIN_S`` past the close; one
@@ -63,6 +71,7 @@ class Rec:
     due: float                       # host clock
     admitted: float = 0.0
     times: List[float] = dataclasses.field(default_factory=list)
+    client: Optional[int] = None     # closed loop: the client that sent it
 
 
 @dataclasses.dataclass
@@ -84,6 +93,7 @@ class Window:
     steps: List[Step]
     lowerings: int
     trace_dir: Optional[str] = None
+    counters: object = None          # repro.obs MetricsRegistry (traced)
 
 
 class _Lowerings:
@@ -98,6 +108,24 @@ class _Lowerings:
     def _on(self, event, duration, **_):
         if event == self.EVENT:
             self.n += 1
+
+
+@contextlib.contextmanager
+def _engine_observed(on: bool):
+    """While on, the engine's spans go to the profiler's trace and its
+    counters to a fresh registry, which this yields; else it yields
+    None and installs nothing."""
+    if not on:
+        yield None
+        return
+    from repro.obs import (Tracer, disable_metrics, disable_tracing,
+                           enable_metrics, enable_tracing)
+    enable_tracing(Tracer(timebase="wall", profiler=True))
+    try:
+        yield enable_metrics()
+    finally:
+        disable_tracing()
+        disable_metrics()
 
 
 def _trace_options():
@@ -141,9 +169,15 @@ class Serving:
 
     # ------------------------------------------------------------------
     def window(self, seconds: float, trace: bool) -> Window:
+        with _engine_observed(trace) as counters:
+            w = self._drive(seconds, trace)
+        w.counters = counters
+        return w
+
+    def _drive(self, seconds: float, trace: bool) -> Window:
         from repro.serving.request import Phase, Request
         eng, mix, seed = self.engine, self.mix, self.seed
-        plan = tmod.open_loop(mix, seconds)
+        arr = tmod.arrivals(mix, seconds)
         recs: List[Rec] = []
         steps: List[Step] = []
         live: Dict[int, Rec] = {}
@@ -152,18 +186,18 @@ class Serving:
         trace_dir, tracing = None, False
         t_trace = (max(0.0, seconds - TRACE_S), seconds)
 
-        def send(due: float, isl: int, osl: int) -> None:
+        def send(due: float, s: tmod.Send) -> None:
             idx = len(recs)
-            req = Request(rid=idx, isl=isl, osl=osl, arrival=due,
-                          prompt=tmod.prompt(seed, idx, isl, self.vocab))
-            rec = Rec(req=req, due=due, admitted=time.perf_counter())
+            req = Request(rid=idx, isl=s.isl, osl=s.osl, arrival=due,
+                          prompt=tmod.prompt(seed, s.key, s.isl, self.vocab))
+            rec = Rec(req=req, due=due, admitted=time.perf_counter(),
+                      client=s.client)
             recs.append(rec)
             live[idx] = rec
             eng.add_request(req)
 
         low0 = self.lowerings.n
         t0 = time.perf_counter()
-        nxt = 0                           # the next arrival
         while True:
             now = time.perf_counter()
             el = now - t0
@@ -177,16 +211,14 @@ class Serving:
                 jax.profiler.stop_trace()
                 tracing = False
             with note("bench.admit"):
-                while nxt < len(plan) and plan[nxt].due_s <= el:
-                    a = plan[nxt]
-                    send(t0 + a.due_s, a.isl, a.osl)
-                    nxt += 1
+                for a in arr.due(el):
+                    send(t0 + a.due_s, a)
             if not live:
-                if nxt >= len(plan):
+                wake = arr.wake(el)
+                if wake is None:
                     break
                 with note("bench.wait"):
-                    time.sleep(max(0.0, t0 + plan[nxt].due_s
-                                   - time.perf_counter()))
+                    time.sleep(max(0.0, t0 + wake - time.perf_counter()))
                 continue
             if el > seconds + DRAIN_S:
                 break
@@ -208,6 +240,7 @@ class Serving:
                         r.times.extend([s1] * (n - before[i]))
                     if r.req.phase == Phase.DONE:
                         del live[i]
+                        arr.finished(r.client)
                 steps.append(Step(s0, s1, dec, pre, traced=tracing))
         if tracing:
             jax.profiler.stop_trace()

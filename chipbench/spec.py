@@ -5,8 +5,18 @@ configuration and traffic mix.  Everything else is a file under this
 directory, found by the name alone:
 
     configs/<config>.json     sizes as run, source, cuts, limits
-    traffic/<mix>.json        arrival process, lengths, engine slots
+    traffic/<mix>.json        arrival process (an open loop at a rate, or
+                              closed-loop clients; ``traffic.py``),
+                              lengths, engine slots
     metrics/<metric>.py       one reader per metric: ``read(run) -> float|None``
+
+A reader gets a ``harness.Run``: the cell, ``setup_s``, the chip's peaks,
+the model's counted shapes and the window's requests and steps; with
+``--trace 1`` also the trace's reduction (``reduced``), the idle time
+under the engine's ``engine.*`` spans (``spans``, ``spans.Spans``) and the
+engine's ``repro_engine_*`` counters over the window (``counters``, a
+``repro.obs`` ``MetricsRegistry``: ``run.counters.counter_total(name)``).
+A reader with nothing to read returns None.
 
 Adding a configuration, a mix or a metric is adding files and entries;
 no existing file changes.

@@ -8,7 +8,9 @@ own lengths, its rate replaced) and prints one JSON line per rate: tokens
 per second completed, TTFT and ITL tails, and the backlog at the close
 (requests due but not yet scheduled).  The knee is the highest rate whose
 TTFT tail stays flat and whose backlog does not grow; cells run at 0.8 x
-the knee.  The benchmark's runs never call this.
+the knee.  Open-loop (``poisson``) mixes only: a closed loop's load
+follows the engine, and has no rate to sweep.  The benchmark's runs never
+call this.
 """
 from __future__ import annotations
 
@@ -33,6 +35,9 @@ def main(argv=None) -> int:
     import jax
     from chipbench import device, harness, serving, spec
     cell = spec.load_cell(args.workload)
+    if cell.traffic["arrivals"]["kind"] != "poisson":
+        ap.error(f"{args.workload} is not an open-loop cell; only a "
+                 f"poisson mix has a rate to sweep")
     harness.use_compile_cache()
     peaks = device.check(jax.devices(), cell.chips)
     sv = serving.Serving(cell, args.seed)

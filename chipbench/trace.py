@@ -223,10 +223,10 @@ def reduce(profile, devices: int = 1) -> Reduced:
                    idle=dict(idle), gaps=gaps[:10])
 
 
-def load(trace_dir: str, devices: int = 1) -> Reduced:
+def profile(trace_dir: str):
+    """The ``ProfileData`` of the trace written under ``trace_dir``."""
     import jax
-    return reduce(jax.profiler.ProfileData.from_file(find_xplane(trace_dir)),
-                  devices)
+    return jax.profiler.ProfileData.from_file(find_xplane(trace_dir))
 
 
 def breakdown(r: Reduced) -> Dict:

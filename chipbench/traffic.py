@@ -2,11 +2,14 @@
 
 A mix file (``traffic/<mix>.json``) is
 
-    {"kind": "open", "arrivals": {...}, "lengths": {...}, "engine": {...}}
+    {"kind": "open"|"closed", "arrivals": {...}, "lengths": {...},
+     "engine": {...}}
 
 The schedule (when each request is due, and its prompt and output
 lengths) is a pure function of ``(mix, seconds)``: every run of a cell,
-whatever its seed, offers the same work at the same times.  The seed
+whatever its seed, offers the same work at the same times.  In a closed
+loop the send times follow the engine, but each client's sequence of
+lengths is a pure function of the mix and the client's index.  The seed
 draws only what the requests say (:func:`prompt`) and, elsewhere, the
 weights.  Were the order drawn from the seed, which long requests fall
 near the close would move the tokens delivered inside a window by up to
@@ -19,6 +22,18 @@ Arrivals (``arrivals.kind``):
                holds ``round(rate * seconds)`` requests whose gaps are the
                exponential distribution's quantiles, in a fixed shuffled
                order, scaled to fill the window.
+  ``closed``   ``clients`` clients, each with at most one request in
+               flight (:func:`clients`).  Client ``i`` sends its first
+               request ``i * stagger_s / clients`` seconds into the
+               window and each next one in the engine step after its
+               previous one finished; a request is due when it is sent.
+               Sending stops at the window's close.  A client's lengths
+               are a cycle of ``cycle`` (default 64) strata pairs, in an
+               order shuffled per client, repeated.
+
+:func:`arrivals` gives the window one object per kind (``OpenArrivals``,
+``ClosedArrivals``) with ``due(el)``, ``finished(client)`` and
+``wake(el)``; a new kind is a new class in ``ARRIVALS``.
 
 Lengths: ``isl`` is ``{"choices": [...], "weights": [...]}`` or
 ``{"lo": a, "hi": b}`` (uniform integers); ``osl`` likewise.  Each window's
@@ -31,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import random
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +96,35 @@ def lengths(mix: Dict, n: int) -> List[Tuple[int, int]]:
     return list(zip(isl, osl))
 
 
+@dataclasses.dataclass(frozen=True)
+class Client:
+    """One closed-loop client: when it first sends, and the (isl, osl)
+    pairs it sends, in order, over and over."""
+    start_s: float               # offset from the window's start
+    cycle: Tuple[Tuple[int, int], ...]
+
+    def request(self, k: int) -> Tuple[int, int]:
+        """The lengths of the client's ``k``-th request (from 0)."""
+        return self.cycle[k % len(self.cycle)]
+
+
+def clients(mix: Dict) -> List[Client]:
+    """The clients of a closed-loop mix."""
+    a = mix["arrivals"]
+    if a["kind"] != "closed":
+        raise ValueError(f"arrival kind {a['kind']!r} is not closed")
+    n, cycle = int(a["clients"]), int(a.get("cycle", 64))
+    out = []
+    for c in range(n):
+        isl = _strata(mix["lengths"]["isl"], cycle)
+        osl = _strata(mix["lengths"]["osl"], cycle)
+        _rng(SCHEDULE, f"client{c}:isl").shuffle(isl)
+        _rng(SCHEDULE, f"client{c}:osl").shuffle(osl)
+        out.append(Client(start_s=c * float(a["stagger_s"]) / n,
+                          cycle=tuple(zip(isl, osl))))
+    return out
+
+
 def prompt_lengths(mix: Dict) -> List[int]:
     """Every prompt length the mix can send: the shapes to warm up."""
     d = mix["lengths"]["isl"]
@@ -117,6 +161,87 @@ def open_loop(mix: Dict, seconds: float) -> List[Arrival]:
         raise ValueError(f"unknown arrival kind {a['kind']!r}")
     sizes = lengths(mix, len(times))
     return [Arrival(t, i, o) for t, (i, o) in zip(times, sizes)]
+
+
+@dataclasses.dataclass(frozen=True)
+class Send:
+    """A request to send now."""
+    due_s: float                 # offset from the window's start
+    isl: int
+    osl: int
+    key: int                     # which prompt (:func:`prompt`'s ``idx``)
+    client: Optional[int] = None
+
+
+class OpenArrivals:
+    """An open loop: each request is due at its time in the schedule."""
+
+    def __init__(self, mix: Dict, seconds: float):
+        self.plan = open_loop(mix, seconds)
+        self.next = 0
+
+    def due(self, el: float) -> Iterator[Send]:
+        """The requests due by ``el`` seconds into the window."""
+        while self.next < len(self.plan) and self.plan[self.next].due_s <= el:
+            a = self.plan[self.next]
+            yield Send(a.due_s, a.isl, a.osl, key=self.next)
+            self.next += 1
+
+    def finished(self, client: Optional[int]) -> None:
+        """A request has finished; an open loop sends regardless."""
+
+    def wake(self, el: float) -> Optional[float]:
+        """When, with nothing in flight, the next request is due; None
+        when none will be."""
+        return self.plan[self.next].due_s if self.next < len(self.plan) \
+            else None
+
+
+class ClosedArrivals:
+    """A closed loop: each client sends once it has started and has no
+    request in flight, until the window closes."""
+
+    def __init__(self, mix: Dict, seconds: float):
+        self.clients = clients(mix)
+        self.seconds = seconds
+        self.idle = set(range(len(self.clients)))   # none in flight
+        self.sent = [0] * len(self.clients)         # requests each sent
+
+    def due(self, el: float) -> Iterator[Send]:
+        if el >= self.seconds:
+            return
+        n = len(self.clients)
+        for c in sorted(self.idle):
+            if self.clients[c].start_s <= el:
+                k = self.sent[c]
+                self.sent[c] += 1
+                self.idle.discard(c)
+                # a client's k-th prompt, whatever order the clients
+                # happen to send in
+                yield Send(el, *self.clients[c].request(k), key=c + n * k,
+                           client=c)
+
+    def finished(self, client: Optional[int]) -> None:
+        self.idle.add(client)
+
+    def wake(self, el: float) -> Optional[float]:
+        starts = [self.clients[c].start_s for c in self.idle
+                  if self.clients[c].start_s > el]
+        return min(starts) if starts and el < self.seconds else None
+
+
+ARRIVALS = {"poisson": OpenArrivals, "closed": ClosedArrivals}
+
+
+def arrivals(mix: Dict, seconds: float):
+    """The mix's arrival process over a window of ``seconds``: ``due(el)``
+    yields what to send ``el`` seconds into the window, ``finished`` is
+    told each request's client as it finishes, and ``wake(el)`` says when
+    to look again while nothing is in flight."""
+    kind = mix["arrivals"]["kind"]
+    if kind not in ARRIVALS:
+        raise ValueError(f"unknown arrival kind {kind!r}")
+    return ARRIVALS[kind](mix, seconds)
 
 
 def prompt(seed: int, idx: int, isl: int, vocab: int) -> List[int]:

@@ -34,6 +34,9 @@ OPEN = {"kind": "open", "arrivals": {"kind": "poisson", "rate_rps": 12.0},
         "lengths": {"isl": {"choices": [16, 32], "weights": [0.5, 0.5]},
                     "osl": {"lo": 4, "hi": 10}},
         "engine": {"slots": 4, "max_seq": 48}}
+# Four clients on four slots, the first sends staggered over 0.4 s.
+CLOSED = dict(OPEN, kind="closed", arrivals={
+    "kind": "closed", "clients": 4, "stagger_s": 0.4, "cycle": 6})
 
 
 def cell(config=DENSE, mix=OPEN):
@@ -85,13 +88,18 @@ def profile_for_steps(steps):
     ``bench.step`` of 40 ms every 50 ms, inside it one prefill program per
     prompt (2 ms each) and then the decode program (30 ms, the same name
     and fingerprint every step), the device's clock 0.2 ms ahead of the
-    host's."""
+    host's.  The engine's ``engine.step`` span takes the step's first
+    39 ms, and a step that decodes reads its tokens back in the last
+    one of them (``engine.decode.sample``)."""
     E, L, P = Event, Line, Plane
     ms = 1_000_000
     host, mods = [], []
     for i, s in enumerate(steps):
         t = 50 * ms * i
-        host += [E("bench.admit", t - ms, ms // 2), E("bench.step", t, 40 * ms)]
+        host += [E("bench.admit", t - ms, ms // 2), E("bench.step", t, 40 * ms),
+                 E("engine.step", t, 39 * ms)]
+        if s.decode_lens:
+            host.append(E("engine.decode.sample", t + 38 * ms, ms))
         at = t - ms // 5
         for n in s.prefill_lens:
             mods.append(E(f"jit__unknown({n})", at, 2 * ms))

@@ -137,3 +137,25 @@ def test_recorded_v5e_trace_with_engine_spans():
     assert s.share("readback") + s.share("host") + \
         s.share(spans.OUTSIDE) == pytest.approx(idle_share)
     assert s.host["engine.step"][0] == len(steps)
+
+
+def test_idle_split_metrics_add_up_on_recorded_v5e_trace():
+    """On the recorded v5e trace, the two engine idle metrics and the
+    idle outside any engine span add up to ``idle_share``, all read by
+    the benchmark's own readers from one profile."""
+    import jax
+    with gzip.open(os.path.join(DATA, "v5e_chat_spans.xplane.pb.gz")) as f:
+        prof = jax.profiler.ProfileData.from_serialized_xspace(f.read())
+    run = types.SimpleNamespace(reduced=trace.reduce(prof, devices=1),
+                                spans=spans.reduce(prof))
+    readback = spec.reader("idle_share.readback")(run)
+    host = spec.reader("idle_share.host")(run)
+    assert readback > 0 and host > 0
+    assert readback + host + run.spans.share(spans.OUTSIDE) == \
+        pytest.approx(spec.reader("idle_share")(run), abs=0.1)
+
+
+def test_idle_split_metrics_read_nothing_without_spans():
+    run = types.SimpleNamespace(reduced=None, spans=None)
+    assert spec.reader("idle_share.readback")(run) is None
+    assert spec.reader("idle_share.host")(run) is None

@@ -80,8 +80,9 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 
 
 def test_recorded_v5e_trace():
-    """A trace recorded on one TPU v5e (``chipbench/record_trace.py``,
-    internlm2-1.8b chat, 32 slots): the names the reduction reads are the
+    """A trace recorded on one TPU v5e (a 1.2 s window of internlm2-1.8b
+    chat, 32 slots; ``chipbench/record_spans.py --out`` writes such a
+    fixture): the names the reduction reads are the
     chip's, every traced engine step is in it, and the decode program runs
     once in each."""
     import jax
